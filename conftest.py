@@ -75,6 +75,10 @@ def pytest_configure(config):
         "allow_thread_exceptions: this test deliberately crashes a helper "
         "thread; the thread-excepthook guard must not fail it",
     )
+    config.addinivalue_line(
+        "markers",
+        "slow: compiles a multi-device program in a subprocess (tens of seconds)",
+    )
 
 _ROOT = os.path.dirname(os.path.abspath(__file__))
 for p in (_ROOT, os.path.join(_ROOT, "src")):

@@ -452,7 +452,7 @@ class HogwildSim:
             # the packed table moves behind the two-tier store for the run;
             # merged() restores the canonical emb_state at the end, so
             # resume/save/eval see exactly the uncached representation
-            store = CachedStore(st.emb_state, self.cache)
+            store = CachedStore(st.emb_state, self.cache, dim=self.spec.dim)
             st.emb_state = None
 
         def _get_batch(it: int):
@@ -795,11 +795,12 @@ class HogwildSim:
     # -- elastic checkpointing (DESIGN.md §8.5) ------------------------------
     def _state_tree(self, st: SimState) -> Dict[str, Any]:
         """Engine-independent on-disk form: dense replicas as the named
-        pytree stack, embedding + optimizer + opaque algorithm state."""
+        pytree stack, the embedding collection in its logical (rows, dim)
+        view, optimizer + opaque algorithm state."""
         return {
             "w": self.dense_stack(st),
             "opt": st.opt_stack,
-            "emb": st.emb_state,
+            "emb": emb.to_logical(st.emb_state, self.spec),
             "algo": st.algo_state,
         }
 
@@ -842,7 +843,13 @@ class HogwildSim:
             or k == "opt" or k.startswith("opt/")
         tree, meta, resized = ckpt.restore_elastic(path, like, may_resize=replica_stacked)
         w_stack = (self.flat.pack_stack(tree["w"]) if self.engine == "flat" else tree["w"])
-        st = SimState(w_stack, tree["opt"], tree["emb"], tree["algo"], int(meta.get("step", 0)))
+        st = SimState(
+            w_stack,
+            tree["opt"],
+            emb.from_logical(tree["emb"], self.spec),
+            tree["algo"],
+            int(meta.get("step", 0)),
+        )
         saved_R = int(meta.get("R", self.R))
         # donors = the restored cohort: rows live at SAVE time (and present
         # after any truncation)
@@ -1979,6 +1986,7 @@ class ThreadedShadowRunner:
             t.join()
         self.done.set()
         sync_restarts = 0
+        supervisor_errors: Dict[str, Any] = {"count": 0, "last": None}
         if sup is not None:
             sync_restarts = sup.restarts("shadow")
             cur = sup.thread("shadow")
@@ -1990,6 +1998,7 @@ class ThreadedShadowRunner:
             sup.deregister("shadow")
             sup.deregister("monitor")
             sup.stop()
+            supervisor_errors = sup.swallowed_errors()
         if shadow_t is not None:
             shadow_t.join(timeout=5.0)
             if shadow_t.is_alive():
@@ -2072,6 +2081,8 @@ class ThreadedShadowRunner:
             ),
             "sync_rounds": self._shadow_rounds,
             "sync_restarts": sync_restarts,
+            # exceptions the supervisor's watch loop caught and survived
+            "supervisor_errors": supervisor_errors,
             "sync_count_at_restart": list(self._sync_count_at_restart),
             "sync_degraded": self._sync_degraded,
             "final_foreground_sync": final_fg_sync,

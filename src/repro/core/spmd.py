@@ -17,6 +17,7 @@ from typing import Any, Callable, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig
 from repro.core import sync as S
@@ -71,6 +72,35 @@ def _accum_grads(
     return jax.tree.map(lambda g: g / k, acc_g), acc_l / k
 
 
+def _explicit_mesh(tree: Pytree):
+    """The mesh of ``tree``'s leaves when it types them with Explicit axes
+    (``jax.make_mesh``'s default since JAX 0.7), else None."""
+    for leaf in jax.tree.leaves(tree):
+        mesh = jax.typeof(leaf).sharding.mesh
+        if not mesh.empty and mesh.are_all_axes_explicit:
+            return mesh
+    return None
+
+
+def _on_auto_axes(step: Callable, out_specs: Callable) -> Callable:
+    """Run ``step`` — model code written for sharding propagation and
+    ``with_sharding_constraint`` hints — with the mesh's axes switched to
+    Auto. On an explicit-sharding mesh the outputs are typed back with
+    ``out_specs(*args)``; on any other input ``step`` runs as is."""
+    def run(*args):
+        mesh = _explicit_mesh(args)
+        if mesh is None:
+            return step(*args)
+        with jax.sharding.use_abstract_mesh(mesh):
+            return jax.sharding.auto_axes(step, out_sharding=out_specs(*args))(*args)
+
+    return run
+
+
+def _specs(tree: Pytree) -> Pytree:
+    return jax.tree.map(lambda x: jax.typeof(x).sharding.spec, tree)
+
+
 def make_train_step(
     cfg: ArchConfig,
     opt: Optimizer,
@@ -100,14 +130,14 @@ def make_train_step(
             p2, st2, loss = jax.vmap(one, spmd_axis_name="pod")(params, opt_state, batch)
             return p2, st2, loss
 
-        return train_step
+        return _on_auto_axes(train_step, lambda p, st, b: (_specs(p), _specs(st), P("pod")))
 
     def train_step(params, opt_state, batch):
         grads, loss = _accum_grads(loss_fn, params, batch, n_microbatches, gdt)
         p2, st2 = opt.update(params, opt_state, grads)
         return p2, st2, loss
 
-    return train_step
+    return _on_auto_axes(train_step, lambda p, st, b: (_specs(p), _specs(st), P()))
 
 
 def make_sync_step(cfg: ArchConfig, sync_cfg: S.SyncConfig) -> Callable:
@@ -121,7 +151,8 @@ def make_sync_step(cfg: ArchConfig, sync_cfg: S.SyncConfig) -> Callable:
     program shape serves them all)."""
     from repro.core import algorithms
 
-    return algorithms.get(sync_cfg.algo).make_sync_step(sync_cfg)
+    step = algorithms.get(sync_cfg.algo).make_sync_step(sync_cfg)
+    return _on_auto_axes(step, lambda params, algo_state=None: (_specs(params), _specs(algo_state)))
 
 
 def make_prefill_step(cfg: ArchConfig, s_max: int) -> Callable:

@@ -145,6 +145,11 @@ class Supervisor:
         self._thread: Optional[threading.Thread] = None
         # hogwild-race: ok — the single watch thread appends; readers snapshot post-run
         self.events: List[SupervisionEvent] = []
+        # exceptions the watch loop caught from check_once / tick: counted
+        # and the latest kept, so a run that survived them still reports them
+        # hogwild-race: ok — written by the single watch thread, read post-run
+        self.swallowed = 0
+        self.last_swallowed: Optional[BaseException] = None  # hogwild-race: ok — same contract
 
     # -- registry ------------------------------------------------------------
     def register(
@@ -221,15 +226,21 @@ class Supervisor:
     # -- the watch loop ------------------------------------------------------
     def _watch_loop(self) -> None:
         while not self._stop.wait(self.config.check_interval_s):
-            try:
-                self.check_once()
-            except Exception:  # supervision must outlive a bad callback
-                pass
-            if self.tick is not None:
+            # supervision must outlive a bad callback — but never in silence
+            for step in (self.check_once, self.tick):
+                if step is None:
+                    continue
                 try:
-                    self.tick()
-                except Exception:
-                    pass
+                    step()
+                except Exception as e:
+                    self.swallowed += 1
+                    self.last_swallowed = e
+
+    def swallowed_errors(self) -> Dict[str, object]:
+        """What the watch loop caught and survived: the count and the
+        latest exception's repr (None when nothing was caught)."""
+        last = self.last_swallowed
+        return {"count": self.swallowed, "last": None if last is None else repr(last)}
 
     def check_once(self) -> List[SupervisionEvent]:
         """One supervision pass (public for deterministic tests: drive it

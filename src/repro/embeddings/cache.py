@@ -3,12 +3,13 @@
 Production CTR tables run to hundreds of GB while an accelerator box holds
 tens; the classical fix is a software-managed cache in front of each
 embedding PS (DESIGN.md §11). ``CachedStore`` fronts ONE contiguous
-(rows, d) table+accumulator pair — a PS shard (``embeddings/shards.py``) or
+table+accumulator pair — a PS shard (``embeddings/shards.py``) or
 ``HogwildSim``'s packed collection — with two tiers:
 
-* a **device-resident hot tier**: a fixed-budget contiguous (H, d) pair, so
-  the existing fused ``embedding_bag`` / ``sparse_adagrad`` kernels run on
-  it UNCHANGED (only row ids are remapped to hot slots);
+* a **device-resident hot tier**: a fixed-budget contiguous pair of H rows,
+  lane-dense like the table it fronts (``kernels/layout.py``), so the
+  existing fused ``embedding_bag`` / ``sparse_adagrad`` kernels run on it
+  UNCHANGED (only row ids are remapped to hot slots);
 * a **host-resident cold store**: plain numpy arrays holding the canonical
   values of every non-resident row (entries for hot rows are stale until
   eviction writes them back).
@@ -46,6 +47,7 @@ from typing import Callable, Dict, List, Optional
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels import layout
 from repro.kernels.embedding_bag.ops import embedding_bag_op
 from repro.kernels.sparse_adagrad.ops import sparse_adagrad_op
 from repro.models.layers import Params
@@ -128,7 +130,7 @@ class TierState:
     """What a reader needs for one consistent lookup/update: the hot arrays
     and the routing that indexes them, published together."""
 
-    hot: Params  # {"table": (H, d), "acc": (H, d)} device arrays
+    hot: Params  # {"table", "acc"}: H slots, lane-dense device arrays
     routing: Routing
 
 
@@ -166,30 +168,39 @@ class _Plan:
 class CachedStore:
     """Two-tier store over one contiguous table. All row ids are LOCAL to
     this store (the caller routes shard-local ids; ``HogwildSim`` passes
-    packed global ids). Thread model: ``lookup``/``update`` are called by
-    trainer threads, ``prefetch`` by the background worker; every placement
-    change happens under ``_lock`` and lands as a fresh ``TierState``."""
+    packed global ids). ``state`` is lane-dense with rows of ``dim``
+    (default: its width, one row per lane row); every lane-row slot is a
+    row here, so ``merged()`` returns the input's layout. Thread model:
+    ``lookup``/``update`` are called by trainer threads, ``prefetch`` by the
+    background worker; every placement change happens under ``_lock`` and
+    lands as a fresh ``TierState``."""
 
-    def __init__(self, state: Params, cfg: CacheConfig, *, eps: float = 1e-8):
+    def __init__(
+        self, state: Params, cfg: CacheConfig, *, dim: Optional[int] = None, eps: float = 1e-8
+    ):
         self.cfg = cfg.validate()
-        self.n_rows, self.dim = state["table"].shape
+        self.width = state["table"].shape[-1]
+        self.dim = dim or self.width
         self.eps = eps
-        H = cfg.resolve_hot_rows(self.n_rows)
-        self.hot_budget = H
-        # Host-resident cold store: canonical for cold rows; hot rows'
-        # entries go stale until eviction writes them back.
+        # Host-resident cold store, one row per row (the lane-dense state's
+        # row view): canonical for cold rows; hot rows' entries go stale
+        # until eviction writes them back.
         # guarded-by: _lock — migrations write back evicted rows under _lock;
         # merged() copies under it too, so readers never see a torn writeback
         self.cold: Dict[str, np.ndarray] = {
-            k: np.array(state[k], dtype=np.float32, copy=True) for k in state
+            k: np.array(layout.row_view(np.asarray(state[k]), self.dim), dtype=np.float32)
+            for k in state
         }
+        self.n_rows, self._row_w = self.cold["table"].shape
+        H = cfg.resolve_hot_rows(self.n_rows)
+        self.hot_budget = H
         # Initial placement: rows [0, H) hot (the data's skew concentrates
         # on low ids; the prefetcher re-derives placement within a round).
         slot = np.full(self.n_rows, -1, np.int32)
         slot[:H] = np.arange(H, dtype=np.int32)
         hot_row = np.full(H, -1, np.int32)
         hot_row[:min(H, self.n_rows)] = np.arange(min(H, self.n_rows), dtype=np.int32)
-        hot = {k: jnp.asarray(self.cold[k][:H]) for k in self.cold}
+        hot = {k: jnp.asarray(layout.pack_rows(self.cold[k][:H], self.width)) for k in self.cold}
         # swap-published; guarded-by-writes: _lock — every placement change
         # lands as a fresh immutable TierState; trainers read lock-free
         self._st = TierState(hot, Routing(slot, hot_row, 0))
@@ -211,9 +222,10 @@ class CachedStore:
         return self._st.routing.slot[rows] >= 0
 
     def merged(self) -> Params:
-        """The canonical full (rows, d) state — cold store overlaid with the
-        live hot tier. Bitwise-exact: hot rows come straight off the device,
-        cold rows were written back exactly on eviction. This is what
+        """The canonical full state, in the input's lane-dense layout — cold
+        store overlaid with the live hot tier. Bitwise-exact: hot rows come
+        straight off the device, cold rows were written back exactly on
+        eviction. This is what
         snapshots, checkpoints, and ``to_packed`` consume: the cache is
         invisible above this line.
 
@@ -227,9 +239,10 @@ class CachedStore:
             out = {k: self.cold[k].copy() for k in self.cold}
         occ = st.routing.hot_row >= 0
         rows = st.routing.hot_row[occ]
+        slots = jnp.asarray(np.flatnonzero(occ))
         for k in out:
-            out[k][rows] = np.asarray(jnp.take(st.hot[k], jnp.asarray(np.flatnonzero(occ)), axis=0))
-        return {k: jnp.asarray(v) for k, v in out.items()}
+            out[k][rows] = np.asarray(layout.take_rows(st.hot[k], slots, self._row_w))
+        return {k: jnp.asarray(layout.pack_rows(v, self.width)) for k, v in out.items()}
 
     def check_invariants(self) -> None:
         """Every row routed to exactly one (tier, slot); slot<->row maps are
@@ -274,7 +287,7 @@ class CachedStore:
             st = self._promote_sync(missing, keep=rows)
             missing = rows[st.routing.slot[rows] < 0]
         slots = st.routing.slot[idx]
-        return embedding_bag_op(st.hot["table"], jnp.asarray(slots))
+        return embedding_bag_op(st.hot["table"], jnp.asarray(slots), dim=self.dim)
 
     def update(self, idx: np.ndarray, g_pooled: jnp.ndarray, lr: float) -> bool:
         """Fused row-sparse Adagrad on the hot tier: idx (..., m) local row
@@ -297,7 +310,7 @@ class CachedStore:
             slots = st.routing.slot[idx]
             table, acc = sparse_adagrad_op(
                 st.hot["table"], st.hot["acc"], jnp.asarray(slots), g_pooled,
-                lr=lr, eps=self.eps)
+                lr=lr, eps=self.eps, dim=self.dim)
             with self._lock:
                 if self._st.routing is st.routing:
                     self._st = TierState({"table": table, "acc": acc}, st.routing)
@@ -354,13 +367,17 @@ class CachedStore:
         if len(plan.evict_rows):
             ev = jnp.asarray(plan.evict_slots)
             for k in hot:
-                self.cold[k][plan.evict_rows] = np.asarray(jnp.take(hot[k], ev, axis=0))
+                self.cold[k][plan.evict_rows] = np.asarray(
+                    layout.take_rows(hot[k], ev, self._row_w)
+                )
             self.stats.evict_rows += len(plan.evict_rows)
             self.stats.writeback_rows += len(plan.evict_rows)
             self.stats.bytes_d2h += len(plan.evict_rows) * self._row_bytes
         dst = jnp.asarray(plan.dst)
         for k in hot:
-            hot[k] = hot[k].at[dst].set(jnp.asarray(self.cold[k][plan.promote]))
+            hot[k] = layout.set_rows(
+                hot[k], dst, jnp.asarray(self.cold[k][plan.promote]), self._row_w
+            )
         self.stats.bytes_h2d += len(plan.promote) * self._row_bytes
         slot = st.routing.slot.copy()
         hot_row = st.routing.hot_row.copy()

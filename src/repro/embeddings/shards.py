@@ -3,9 +3,10 @@
 ``plan_shards`` runs the greedy LPT planner (``table.bin_pack`` over
 ``table.lookup_costs``) to assign whole categorical tables to ``n_shards``
 embedding PSs — the paper's load balancing (§3.1). ``ShardPlan`` freezes that
-assignment plus the derived routing arrays; the packed (total_rows, dim)
-collection splits into one contiguous (shard_rows, dim) array per PS, each with
-its co-located Adagrad accumulator.
+assignment plus the derived routing arrays; the packed collection of
+``total_rows`` rows splits into one contiguous store of ``shard_rows`` rows
+per PS, each with its co-located Adagrad accumulator, each lane-dense
+(``kernels/layout.py``) like the collection it came from.
 
 Lookups route by the plan: each shard answers one fused lookup+pool kernel
 launch over its own features, and the pooled planes reassemble in feature
@@ -42,6 +43,7 @@ exactly what they saw before — at the price that a snapshot drains the hot
 tier (O(hot_rows) device reads) instead of being an O(1) reference grab."""
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from dataclasses import dataclass
@@ -58,6 +60,7 @@ from repro.embeddings.table import (
     init_tables,
     lookup_costs,
 )
+from repro.kernels import layout
 from repro.kernels.embedding_bag.ops import embedding_bag_op
 from repro.kernels.sparse_adagrad.ops import sparse_adagrad_op
 from repro.models.layers import Params
@@ -101,25 +104,43 @@ def plan_shards(spec: TableSpec, n_shards: int, batch_size: int) -> ShardPlan:
     )
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def shard_states(plan: ShardPlan, state: Params) -> List[Params]:
-    """Split a packed {"table", "acc"} state into per-shard states (each shard
-    concatenates its tables' global row ranges in bin order)."""
-    goff = plan.spec.offsets
-    out = []
-    for feats in plan.bins:
-        parts = [(int(goff[f]), int(goff[f]) + plan.spec.sizes[f]) for f in feats]
-        out.append({k: jnp.concatenate([state[k][a:b] for a, b in parts]) for k in state})
-    return out
+    """Split a lane-dense {"table", "acc"} collection into lane-dense
+    per-shard states (each shard concatenates its tables' global row ranges
+    in bin order). When every table starts and ends on a lane-row boundary
+    (``dlrm-ctr``'s even sizes at d=64) the split copies whole lane rows;
+    otherwise it goes through the logical rows, in one program."""
+    d, goff, sizes = plan.spec.dim, plan.spec.offsets, plan.spec.sizes
+    p = layout.rows_per_lane_row(state["table"].shape[-1], d)
+    ranges = [[(int(goff[f]), int(goff[f]) + sizes[f]) for f in feats] for feats in plan.bins]
+    if all(int(o) % p == 0 and n % p == 0 for o, n in zip(goff, sizes)):
+        return [
+            {k: jnp.concatenate([v[a // p : b // p] for a, b in parts]) for k, v in state.items()}
+            for parts in ranges
+        ]
+    rows = {k: layout.unpack(v, plan.spec.total_rows, d) for k, v in state.items()}
+    return [
+        {k: layout.pack(jnp.concatenate([v[a:b] for a, b in parts])) for k, v in rows.items()}
+        for parts in ranges
+    ]
 
 
 def packed_state(plan: ShardPlan, states: List[Params]) -> Params:
-    """Inverse of ``shard_states``: reassemble the global packed state."""
+    """Inverse of ``shard_states``, in the logical view: the global
+    (total_rows, dim) {"table", "acc"} as host numpy arrays — what
+    checkpoints and run outputs carry."""
+    d = plan.spec.dim
+    logical = [
+        {k: layout.unpack(np.asarray(v), plan.shard_rows[s], d) for k, v in st.items()}
+        for s, st in enumerate(states)
+    ]
     parts = {k: [None] * len(plan.spec.sizes) for k in states[0]}
     for f in range(len(plan.spec.sizes)):
         s, loff = plan.feature_shard[f], plan.feature_local_offset[f]
         for k in parts:
-            parts[k][f] = states[s][k][loff:loff + plan.spec.sizes[f]]
-    return {k: jnp.concatenate(v) for k, v in parts.items()}
+            parts[k][f] = logical[s][k][loff:loff + plan.spec.sizes[f]]
+    return {k: np.concatenate(v) for k, v in parts.items()}
 
 
 def _route(plan: ShardPlan, s: int, idx: jnp.ndarray) -> jnp.ndarray:
@@ -151,7 +172,11 @@ def shard_lookup(
     launch per shard."""
     outs = [
         embedding_bag_op(
-            tables[s], _route(plan, s, idx), use_pallas=use_pallas, interpret=interpret
+            tables[s],
+            _route(plan, s, idx),
+            dim=plan.spec.dim,
+            use_pallas=use_pallas,
+            interpret=interpret,
         )
         for s in range(plan.n_shards)
     ]
@@ -178,7 +203,13 @@ def shard_update(
     loc = _route(plan, s, idx).reshape(-1, m)
     g = jnp.take(g_pooled, jnp.asarray(plan.bins[s]), axis=1).reshape(-1, d)
     table, acc = sparse_adagrad_op(
-        state_s["table"], state_s["acc"], loc, g, lr=lr, eps=eps,
+        state_s["table"],
+        state_s["acc"],
+        loc,
+        g,
+        lr=lr,
+        eps=eps,
+        dim=d,
         use_pallas=use_pallas, interpret=interpret)
     return {"table": table, "acc": acc}
 
@@ -277,7 +308,7 @@ class EmbeddingShards:
             # The stores OWN the live values; states[] stays None so any
             # uncached-path access fails loudly instead of reading a fork.
             self.states = [None] * n
-            self.stores = [CachedStore(st, self.cache) for st in states]
+            self.stores = [CachedStore(st, self.cache, dim=plan.spec.dim) for st in states]
 
     @classmethod
     def init(
@@ -367,9 +398,14 @@ class EmbeddingShards:
             st = self.states[s]
             # health is the authority, not just None-ness (see tables())
             if st is not None and self.health[s]:
-                return embedding_bag_op(st["table"], _route(self.plan, s, jnp.asarray(idx)))
+                return self._lookup_table(st["table"], s, idx)
         self.stale_lookups[s] += 1
-        return embedding_bag_op(self.snapshots[s]["table"], _route(self.plan, s, jnp.asarray(idx)))
+        return self._lookup_table(self.snapshots[s]["table"], s, idx)
+
+    def _lookup_table(self, table: jnp.ndarray, s: int, idx: np.ndarray) -> jnp.ndarray:
+        return embedding_bag_op(
+            table, _route(self.plan, s, jnp.asarray(idx)), dim=self.plan.spec.dim
+        )
 
     def assemble(self, outs: List[jnp.ndarray]) -> jnp.ndarray:
         """Reassemble the per-shard pooled planes (bins order) into the
@@ -499,7 +535,7 @@ class EmbeddingShards:
             # default; the prefetcher re-derives it within a round). The
             # build moves whole tables host->device, so it runs OUTSIDE
             # the lock; a down shard's snapshot cannot advance meanwhile.
-            store = CachedStore(snap, self.cache)
+            store = CachedStore(snap, self.cache, dim=self.plan.spec.dim)
         with self._lock:
             if self.health[s]:
                 return  # a concurrent recovery beat us to it
@@ -516,7 +552,8 @@ class EmbeddingShards:
         return [s for s in range(self.plan.n_shards) if not self.health[s]]
 
     def to_packed(self) -> Params:
-        """The engine-independent packed {"table", "acc"} view. A failed
+        """The engine-independent packed {"table", "acc"} view: host numpy
+        in the logical (total_rows, dim) layout (``packed_state``). A failed
         shard contributes its snapshot (the best surviving copy). Cached
         shards contribute ``merged()`` — the cache-invisibility contract:
         checkpoints and the sync oracle see the canonical full tables."""

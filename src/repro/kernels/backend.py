@@ -25,7 +25,7 @@ def resolve_interpret(interpret: Optional[bool]) -> bool:
 
 def resolve_strategy(strategy: Optional[str], *, tpu: str, fallback: str) -> str:
     """Pick a kernel grid strategy per backend: ``tpu`` names the
-    fine-grid streaming kernel compiled on TPU, ``fallback`` the
+    fine-grid DMA kernel compiled on TPU, ``fallback`` the
     coarse-grid variant that stays fast through the interpreter (its
     per-grid-step cost is a buffer copy). Explicit ``strategy`` wins."""
     if strategy is None:

@@ -1,14 +1,19 @@
 """Pallas TPU kernels: fused embedding-bag (gather + sum-pool).
 
 The paper's embedding PSs spend their cycles on exactly this op (lookup + partial
-pooling, §3.1). Two grid strategies over the same semantics (DESIGN.md §7):
+pooling, §3.1). Tables are lane-dense (``kernels/layout.py``): a ``(lane_rows,
+width)`` array holding ``p = width // dim`` logical rows per lane row. Two grid
+strategies over the same semantics (DESIGN.md §7.1):
 
-* ``embedding_bag`` — row-streaming. Scalar-prefetch the row ids and let the
-  BlockSpec index_map stream one table row per grid step HBM->VMEM, accumulating
-  the pool in the revisited output block. Grid = (n_bags, multi_hot); the output
-  block for bag ``n`` is revisited across the ``m`` axis (sequential TPU grid),
-  so accumulation needs no scratch. The table never has to fit in VMEM — this is
-  the production-scale path, compiled on TPU.
+* ``embedding_bag`` — DMA gather. The table stays in HBM (``pl.ANY``). Grid =
+  (n_bags / 1024,); each step reads its bags' ``m`` lane-row ids from ``m``
+  SMEM blocks of 1024, DMAs one lane row per id into a ``(m, 1024, width)``
+  VMEM scratch (at most ``DMA_WINDOW`` bags' copies in flight), and
+  sum-pools in-body: for each of the ``p`` lane
+  segments a masked sum, then the segments fold into the ``(1024, dim)``
+  output block. No block is narrower than a full lane row and the ids
+  never sit in SMEM all at once, so this compiles at production widths. This
+  is the path compiled on TPU.
 
 * ``embedding_bag_blocked`` — bag-blocked. Grid = (n_bags / block_bags,); the
   table is a single VMEM-resident block and each grid step gathers + pools a
@@ -18,43 +23,96 @@ pooling, §3.1). Two grid strategies over the same semantics (DESIGN.md §7):
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import rows_per_lane_row
 
-def _kernel(idx_ref, table_ref, out_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    out_ref[...] += table_ref[...].astype(out_ref.dtype)
+# An SMEM block of 1-D int32 ids must be a multiple of 1024 long; the ids
+# arrive id-major (all bags' j-th ids, then the (j+1)-th), one block per j.
+BLOCK_BAGS = 1024
+# Bags whose row DMAs may be in flight at once (x m copies): a bounded
+# window, never a whole block's 1024 x m copies queued at the DMA engine.
+DMA_WINDOW = 32
 
 
-def embedding_bag(table: jnp.ndarray, idx: jnp.ndarray, *, interpret: bool = False) -> jnp.ndarray:
-    """table: (rows, d); idx: (n_bags, m) int32 global row ids -> (n_bags, d) sums.
+def _gather_kernel(*refs, m, p, dim):
+    ids_refs, (seg_ref, table_hbm, out_ref, buf, sem) = refs[:m], refs[m:]
+    tb = buf.shape[1]
 
-    d should be a multiple of 128 on real TPU; the ops.py wrapper pads."""
+    def wait_bag():
+        # every copy moves one lane row: a same-shaped descriptor waits one out
+        for j in range(m):
+            pltpu.make_async_copy(table_hbm.at[pl.ds(0, 1)], buf.at[j, pl.ds(0, 1)], sem).wait()
+
+    def start_bag(i, carry):
+        for j in range(m):
+            pltpu.make_async_copy(table_hbm.at[pl.ds(ids_refs[j][i], 1)],
+                                  buf.at[j, pl.ds(i, 1)], sem).start()
+
+        @pl.when(i >= DMA_WINDOW)
+        def _():
+            wait_bag()
+
+        return carry
+
+    def drain(i, carry):
+        wait_bag()
+        return carry
+
+    jax.lax.fori_loop(0, tb, start_bag, 0)
+    jax.lax.fori_loop(0, min(tb, DMA_WINDOW), drain, 0)
+
+    width = buf.shape[-1]
+    segs = [jnp.zeros((tb, width), jnp.float32) for _ in range(p)]
+    seg = seg_ref[...]  # (tb, m) lane segment of each id
+    for j in range(m):
+        x = buf[j].astype(jnp.float32)
+        if p == 1:
+            segs[0] = segs[0] + x
+        else:
+            s = seg[:, j:j + 1]
+            for k in range(p):
+                segs[k] = segs[k] + jnp.where(s == k, x, 0.0)
+    out = segs[0][:, :dim]
+    for k in range(1, p):
+        out = out + segs[k][:, k * dim:(k + 1) * dim]
+    out_ref[...] = out
+
+
+def embedding_bag(table: jnp.ndarray, idx: jnp.ndarray, *, dim: int,
+                  interpret: bool = False) -> jnp.ndarray:
+    """table: (lane_rows, width) lane-dense, ``p = width // dim`` rows per lane
+    row (1 if dim does not divide width); idx: (n_bags, m) int32 logical row
+    ids, n_bags a multiple of ``BLOCK_BAGS`` -> (n_bags, dim) sums."""
     n_bags, m = idx.shape
-    _, d = table.shape
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(n_bags, m),
-        in_specs=[
-            pl.BlockSpec((1, d), lambda n, j, idx_ref: (idx_ref[n, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, d), lambda n, j, idx_ref: (n, 0)),
-    )
+    width = table.shape[-1]
+    p = rows_per_lane_row(width, dim)
+    tb = BLOCK_BAGS
+    assert n_bags % tb == 0, (n_bags, tb)
+    nb = n_bags // tb
+    # id-major flattening: a transpose is cheap where flattening the narrow
+    # (n_bags, m) array directly costs the TPU compiler seconds
+    lane_ids = (idx // p).T.reshape(-1)
+    smem = [pl.BlockSpec((tb,), lambda b, j=j: (j * nb + b,), memory_space=pltpu.SMEM)
+            for j in range(m)]
     return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_bags, d), jnp.float32),
+        functools.partial(_gather_kernel, m=m, p=p, dim=dim),
+        grid=(nb,),
+        in_specs=smem + [
+            pl.BlockSpec((tb, m), lambda b: (b, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((tb, dim), lambda b: (b, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_bags, dim), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((m, tb, width), table.dtype),
+                        pltpu.SemaphoreType.DMA(())],
         interpret=interpret,
-    )(idx, table)
+    )(*[lane_ids] * m, idx % p, table)
 
 
 def _blocked_kernel(idx_ref, table_ref, out_ref):
@@ -73,10 +131,11 @@ def embedding_bag_blocked(
     block_bags: int = 512,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """table: (rows, d); idx: (n_bags, m) int32 -> (n_bags, d) sums.
+    """table: (rows, w) one logical row per row (``layout.row_view``); idx:
+    (n_bags, m) int32 -> (n_bags, w) sums.
 
     n_bags must be a multiple of ``block_bags`` (the ops.py wrapper pads); the
-    whole table is one resident block, so rows * d must fit in VMEM — fine for
+    whole table is one resident block, so rows * w must fit in VMEM — fine for
     plan-sharded tables and for the interpreter, not for a monolithic
     production table (use ``embedding_bag`` there)."""
     n_bags, m = idx.shape

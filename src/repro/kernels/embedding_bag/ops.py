@@ -1,16 +1,16 @@
 """Jitted public wrapper for the embedding-bag kernels.
 
 Flattens arbitrary bag batch dims and picks the grid strategy per backend: the
-row-streaming kernel compiled on TPU (the table never has to fit in VMEM), the
-bag-blocked kernel through the interpreter elsewhere (coarse grid — the
-interpreter's cost is per grid step). Both are the same fused lookup+pool
-launch; ``strategy`` forces one explicitly and ``use_pallas=False`` falls back
-to the pure-jnp oracle.
+DMA-gather kernel compiled on TPU (the table stays in HBM), the bag-blocked
+kernel through the interpreter elsewhere (coarse grid — the interpreter's cost
+is per grid step). Both are the same fused lookup+pool launch; ``strategy``
+forces one explicitly and ``use_pallas=False`` falls back to the pure-jnp
+oracle.
 
-d is padded to the TPU lane width ONLY on the compiled path — the interpreter
-has no lane constraint, and the pad/slice would copy the whole table per call.
-Compiled TPU deployments should size d to a multiple of 128 so the per-call
-pad vanishes there too."""
+Tables arrive lane-dense (``kernels/layout.py``): ``dim`` is the logical row
+width, and a plain ``(rows, dim)`` table is the one-row-per-lane-row case. No
+call pads, slices or relayouts the table; the compiled path only needs its
+width to be a multiple of 128, which ``embeddings.table.init_tables`` stores."""
 from __future__ import annotations
 
 import functools
@@ -20,47 +20,49 @@ import jax.numpy as jnp
 
 from repro.kernels.backend import resolve_interpret, resolve_strategy
 from repro.kernels.embedding_bag.embedding_bag import (
+    BLOCK_BAGS,
     embedding_bag,
     embedding_bag_blocked,
 )
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
+from repro.kernels.layout import row_view
 
-LANE = 128
+
+def _pad_bags(idx: jnp.ndarray, multiple: int) -> jnp.ndarray:
+    """Pad (n_bags, m) ids to a bag multiple; padded bags look up row 0 and
+    are sliced off by the caller."""
+    pad = (-idx.shape[0]) % multiple
+    return jnp.pad(idx, ((0, pad), (0, 0))) if pad else idx
 
 
 @functools.partial(
-    jax.jit, static_argnames=("use_pallas", "interpret", "strategy", "block_bags")
+    jax.jit,
+    static_argnames=("dim", "use_pallas", "interpret", "strategy", "block_bags"),
 )
 def embedding_bag_op(
     table: jnp.ndarray,
     idx: jnp.ndarray,
     *,
+    dim: int | None = None,
     use_pallas: bool = True,
     interpret: bool | None = None,
     strategy: str | None = None,
     block_bags: int = 512,
 ) -> jnp.ndarray:
-    """table: (rows, d); idx: (..., m) -> (..., d) sum-pooled lookups."""
-    if not use_pallas:
-        out = embedding_bag_ref(table, idx.reshape(-1, idx.shape[-1]))
-        return out.reshape(*idx.shape[:-1], table.shape[-1])
-    d = table.shape[-1]
-    interp = resolve_interpret(interpret)
-    pad = 0 if interp else (-d) % LANE
-    if pad:
-        table = jnp.pad(table, ((0, 0), (0, pad)))
+    """table: lane-dense (lane_rows, width) holding rows of ``dim`` (default:
+    width, one row per lane row); idx: (..., m) -> (..., dim) sum-pooled."""
+    dim = dim or table.shape[-1]
     flat_idx = idx.reshape(-1, idx.shape[-1]).astype(jnp.int32)
-    if resolve_strategy(strategy, tpu="stream", fallback="block") == "stream":
-        out = embedding_bag(table, flat_idx, interpret=interp)
+    n_bags = flat_idx.shape[0]
+    if not use_pallas:
+        out = embedding_bag_ref(table, flat_idx, dim)
+    elif resolve_strategy(strategy, tpu="stream", fallback="block") == "stream":
+        out = embedding_bag(
+            table, _pad_bags(flat_idx, BLOCK_BAGS),
+            dim=dim, interpret=resolve_interpret(interpret))[:n_bags]
     else:
-        n_bags = flat_idx.shape[0]
         bb = min(block_bags, n_bags)
-        bag_pad = (-n_bags) % bb
-        if bag_pad:  # padded bags look up row 0 and are sliced off below
-            flat_idx = jnp.pad(flat_idx, ((0, bag_pad), (0, 0)))
         out = embedding_bag_blocked(
-            table, flat_idx, block_bags=bb, interpret=interp
-        )[:n_bags]
-    if pad:
-        out = out[:, :d]
-    return out.reshape(*idx.shape[:-1], d)
+            row_view(table, dim), _pad_bags(flat_idx, bb), block_bags=bb,
+            interpret=resolve_interpret(interpret))[:n_bags, :dim]
+    return out.reshape(*idx.shape[:-1], dim)

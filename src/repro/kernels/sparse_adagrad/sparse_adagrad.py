@@ -6,20 +6,25 @@ accumulator update ``acc[r] += g^2`` and the rsqrt-scaled row add
 ACCUMULATE semantics matching the pytree oracle exactly: every occurrence's
 ``g^2`` lands in the accumulator first, and the row step is scaled by that
 FINAL accumulator (``embeddings.table.sparse_adagrad_update`` computes the
-same thing via scatter-add + gather). The pooled gradient of a bag is read
-straight from ``g_pooled`` — the (n_items, d) per-occurrence broadcast the
-unfused path materializes never exists.
+same thing via scatter-add + gather).
 
-Two grid strategies over the same semantics (DESIGN.md §7):
+Tables are lane-dense (``kernels/layout.py``): ``p = width // dim`` logical
+rows share one 128-lane row. Two grid strategies over the same semantics
+(DESIGN.md §7.1):
 
-* ``sparse_adagrad_rows`` — row-streaming. Occurrences arrive SORTED BY ROW
-  (the ops.py wrapper sorts), so duplicates form consecutive grid steps and
-  the revisited table/acc output blocks stay VMEM-resident across a run. A
-  VMEM scratch accumulates the run's gradient sum; every step rewrites the
-  resident table block with the current partial step, so the final (correct)
-  write is the one flushed to HBM. Tables stay in HBM — one (1, d) row block
-  moves per grid step. Aliased in/out: untouched rows are never streamed.
-  This is the production-scale path, compiled on TPU.
+* ``sparse_adagrad_rows`` — run-walking DMA. Occurrences are sorted by LANE
+  row (stable, so duplicates keep their order) and lays each one's
+  pooled gradient at its row's lane segment, zeros elsewhere. Grid =
+  (n_items / 1024,): each step takes 1024 sorted occurrences — their lane
+  row ids and run flags from an SMEM block, their gradients as a (1024,
+  width) VMEM block — and walks them. A run's first occurrence DMAs the acc
+  and table lane rows HBM->VMEM; every occurrence adds ``g^2`` and ``g``; the
+  run's last applies the final-acc-scaled step and DMAs both rows back.
+  Lanes of a lane row that no occurrence touched see ``+0`` and ``-0``: two
+  rows sharing one lane row never lose each other's update. The VMEM
+  run state persists across grid steps, so a run may straddle blocks.
+  Tables stay in HBM (``pl.ANY``) and are aliased in/out: untouched lane
+  rows are never read or written. This is the path compiled on TPU.
 
 * ``sparse_adagrad_blocked`` — occurrence-blocked. Grid = (n_items / block,);
   table, acc, and g_pooled are VMEM-resident blocks, each step scatter-adds a
@@ -37,66 +42,127 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.layout import rows_per_lane_row
 
-def _rows_kernel(rows_ref, bags_ref, g_ref, table_ref, acc_ref,
-                 out_table_ref, out_acc_ref, sum_ref, *, lr: float, eps: float):
-    i = pl.program_id(0)
-    # First occurrence of a row's (sorted, hence consecutive) run: seed the
-    # resident acc block from HBM and zero the run's gradient-sum scratch.
-    first = (i == 0) | (rows_ref[i] != rows_ref[jnp.maximum(i - 1, 0)])
-    g = g_ref[...].astype(jnp.float32)  # (1, d) pooled grad of this bag
+# Occurrences per grid step: an SMEM block of 1-D int32 must be a multiple
+# of 1024 long.
+ITEMS_PER_BLOCK = 1024
+FIRST, LAST = 1, 2  # run flags, in the low bits of each code
 
-    @pl.when(first)
-    def _():
-        out_acc_ref[...] = acc_ref[...]
-        sum_ref[...] = jnp.zeros_like(sum_ref)
 
-    out_acc_ref[...] += g * g
-    sum_ref[...] += g
-    # Rewritten every step of the run; only the last (full-sum, final-acc)
-    # write survives the flush — exactly the oracle's final-acc scaling.
-    scale = lr * jax.lax.rsqrt(out_acc_ref[...] + eps)
-    out_table_ref[...] = (
-        table_ref[...].astype(jnp.float32) - scale * sum_ref[...]
-    ).astype(out_table_ref.dtype)
+def _rows_kernel(code_ref, g_ref, table_in, acc_in, table_ref, acc_ref,
+                 t_row, a_row, g_sum, sem, *, lr: float, eps: float,
+                 n_items: int):
+    del table_in, acc_in  # aliased to table_ref / acc_ref
+    block = pl.program_id(0)
+
+    def move_rows(q, to_hbm):
+        # the run's acc and table lane rows, both copies in flight at once
+        pairs = [(acc_ref.at[pl.ds(q, 1)], a_row), (table_ref.at[pl.ds(q, 1)], t_row)]
+        copies = [pltpu.make_async_copy(*(pair[::-1] if to_hbm else pair), sem.at[k])
+                  for k, pair in enumerate(pairs)]
+        for c in copies:
+            c.start()
+        for c in copies:
+            c.wait()
+
+    def occurrence(i, carry):
+        @pl.when(block * ITEMS_PER_BLOCK + i < n_items)
+        def _():
+            code = code_ref[i]
+            q = code >> 2  # lane row
+
+            @pl.when((code & FIRST) != 0)
+            def _():
+                move_rows(q, to_hbm=False)
+                g_sum[...] = jnp.zeros_like(g_sum)
+
+            g = g_ref[pl.ds(i, 1), :]
+            a_row[...] += g * g
+            g_sum[...] += g
+
+            @pl.when((code & LAST) != 0)
+            def _():
+                scale = lr * jax.lax.rsqrt(a_row[...] + eps)
+                t_row[...] = (t_row[...].astype(jnp.float32)
+                              - scale * g_sum[...]).astype(t_row.dtype)
+                move_rows(q, to_hbm=True)
+
+        return carry
+
+    jax.lax.fori_loop(0, ITEMS_PER_BLOCK, occurrence, 0)
+
+
+def _run_layout(idx, g, width, dim):
+    """Occurrences sorted by lane row (stable), each coded ``lane_row * 4 +
+    FIRST|LAST`` and its gradient laid at its lane segment (zeros elsewhere),
+    padded to whole kernel blocks. Occurrences are taken id-major (hot index
+    j of every bag, then j + 1): a transpose is cheap where flattening the
+    narrow (n_bags, m) array directly costs the TPU compiler seconds; the
+    stable sort keeps each row's duplicates in this order."""
+    n_bags, m = idx.shape
+    rows = idx.T.reshape(-1)
+    bags = jnp.tile(jnp.arange(n_bags, dtype=jnp.int32), m)
+    p = rows_per_lane_row(width, dim)
+    q = rows // p
+    order = jnp.argsort(q, stable=True)
+    q, seg, bags = q[order], (rows % p)[order], bags[order]
+    new = q[1:] != q[:-1]
+    first = jnp.concatenate([jnp.ones((1,), bool), new])
+    last = jnp.concatenate([new, jnp.ones((1,), bool)])
+    code = q * 4 + jnp.where(first, FIRST, 0) + jnp.where(last, LAST, 0)
+    g = jnp.take(g, bags, axis=0)
+    if p > 1:
+        g = jnp.concatenate([jnp.where((seg == k)[:, None], g, 0.0) for k in range(p)], axis=1)
+    elif width > dim:
+        g = jnp.pad(g, ((0, 0), (0, width - dim)))
+    pad = (-q.shape[0]) % ITEMS_PER_BLOCK
+    return jnp.pad(code, (0, pad)), jnp.pad(g, ((0, pad), (0, 0)))
 
 
 def sparse_adagrad_rows(
     table: jnp.ndarray,
     acc: jnp.ndarray,
-    rows: jnp.ndarray,
-    bags: jnp.ndarray,
+    idx: jnp.ndarray,
     g_pooled: jnp.ndarray,
     *,
+    dim: int,
     lr: float,
     eps: float = 1e-8,
     interpret: bool = False,
 ):
-    """table: (n_rows, d); acc: (n_rows, d) fp32; rows/bags: (n_items,) int32
-    sorted by row; g_pooled: (n_bags, d). Returns (new_table, new_acc);
-    rows not referenced are bit-identical (aliased in/out)."""
-    n_items = rows.shape[0]
-    _, d = table.shape
-    row_spec = pl.BlockSpec((1, d), lambda i, rows_ref, bags_ref: (rows_ref[i], 0))
-    bag_spec = pl.BlockSpec((1, d), lambda i, rows_ref, bags_ref: (bags_ref[i], 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_items,),
-        in_specs=[bag_spec, row_spec, row_spec],
-        out_specs=[row_spec, row_spec],
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-    )
+    """table / acc: (lane_rows, width) lane-dense, ``p = width // dim`` rows
+    per lane row (1 if dim does not divide width), acc fp32; idx: (n_bags,
+    m) int32 logical row ids; g_pooled: (n_bags, dim) fp32. Returns
+    (new_table, new_acc); untouched lane rows are bit-identical (aliased
+    in/out)."""
+    n_items = idx.size
+    code, g_lanes = _run_layout(idx, g_pooled, table.shape[-1], dim)
+    n_pad, width = g_lanes.shape
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
     return pl.pallas_call(
-        functools.partial(_rows_kernel, lr=lr, eps=eps),
-        grid_spec=grid_spec,
+        functools.partial(_rows_kernel, lr=lr, eps=eps, n_items=n_items),
+        grid=(n_pad // ITEMS_PER_BLOCK,),
+        in_specs=[
+            pl.BlockSpec((ITEMS_PER_BLOCK,), lambda b: (b,), memory_space=pltpu.SMEM),
+            pl.BlockSpec((ITEMS_PER_BLOCK, width), lambda b: (b, 0)),
+            hbm, hbm,
+        ],
+        out_specs=[hbm, hbm],
         out_shape=[
             jax.ShapeDtypeStruct(table.shape, table.dtype),
             jax.ShapeDtypeStruct(acc.shape, jnp.float32),
         ],
-        # operand order incl. scalar prefetch: (rows, bags, g, table, acc)
-        input_output_aliases={3: 0, 4: 1},
+        scratch_shapes=[
+            pltpu.VMEM((1, width), table.dtype),
+            pltpu.VMEM((1, width), jnp.float32),
+            pltpu.VMEM((1, width), jnp.float32),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        # operand order: (code, g_lanes, table, acc)
+        input_output_aliases={2: 0, 3: 1},
         interpret=interpret,
-    )(rows, bags, g_pooled, table, acc)
+    )(code, g_lanes, table, acc)
 
 
 def _blocked_kernel(rows_ref, bags_ref, g_ref, table_ref, acc_ref,
@@ -137,9 +203,11 @@ def sparse_adagrad_blocked(
     block_items: int = 1024,
     interpret: bool = False,
 ):
-    """Same contract as ``sparse_adagrad_rows`` but rows/bags need not be
-    sorted; n_items must be a multiple of ``block_items`` (the ops.py wrapper
-    pads with zero-gradient occurrences)."""
+    """table / acc: (rows, w) one logical row per row (``layout.row_view``),
+    acc fp32; rows/bags: (n_items,) int32 occurrence row and bag ids, in any
+    order; g_pooled: (n_bags, w). n_items must be a multiple of
+    ``block_items`` (the ops.py wrapper pads with zero-gradient
+    occurrences). Returns (new_table, new_acc), aliased in/out."""
     n_items = rows.shape[0]
     n_rows, d = table.shape
     n_bags = g_pooled.shape[0]

@@ -45,6 +45,7 @@ from repro.core.runners import HogwildSim, ThreadedShadowRunner
 from repro.core.scheduler import PolicyConfig, StragglerPolicy
 from repro.core.sync import SyncConfig
 from repro.embeddings.cache import CacheConfig
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _parse_slot_map(spec, cast):
@@ -190,6 +191,10 @@ def run_dlrm(args) -> dict:
             pipeline=pipeline,
             mode_controller=mode_ctl,
         )
+        # compile every program on throwaway state first: a first call that
+        # compiles inside the run would read as a stalled thread to the
+        # supervisor (heartbeat deadline) and as a straggler to the policy
+        runner.warmup()
         out = runner.run(args.iters)
         if args.auto_mode:
             print(
@@ -348,7 +353,7 @@ def run_lm(args) -> dict:
     return {"loss_start": float(np.mean(losses[:5])), "loss_end": float(np.mean(losses[-5:]))}
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     sub = ap.add_subparsers(dest="cmd", required=True)
 
@@ -526,8 +531,12 @@ def main():
     l.add_argument("--lr", type=float, default=1e-3)
     l.add_argument("--optimizer", default="adam")
     l.add_argument("--seed", type=int, default=0)
+    return ap
 
-    args = ap.parse_args()
+
+def main():
+    use_compile_cache()
+    args = build_parser().parse_args()
     out = run_dlrm(args) if args.cmd == "dlrm" else run_lm(args)
     print(json.dumps(out, default=float))
 

@@ -67,4 +67,8 @@ def constrain(x, spec: Sequence) -> jax.Array:
         if ax is not None and (dim < _axis_size(ctx.mesh, ax) or dim % _axis_size(ctx.mesh, ax)):
             ax = None
         resolved.append(ax)
+    if not jax.sharding.get_abstract_mesh().empty:
+        # tracing under a mesh context (``core.spmd``'s Auto-axes view of
+        # an explicit-sharding mesh): the spec binds to that mesh's axes
+        return jax.lax.with_sharding_constraint(x, P(*resolved))
     return jax.lax.with_sharding_constraint(x, NamedSharding(ctx.mesh, P(*resolved)))

@@ -17,6 +17,7 @@ from repro.core.runners import HogwildSim, ThreadedShadowRunner
 from repro.core.sync import SyncConfig
 from repro.embeddings import shards
 from repro.embeddings import table as emb
+from repro.kernels import layout
 from repro.kernels.embedding_bag.ops import embedding_bag_op
 from repro.kernels.embedding_bag.ref import embedding_bag_ref
 from repro.kernels.sparse_adagrad.ops import sparse_adagrad_op
@@ -143,6 +144,62 @@ class TestEmbeddingBagBlocked:
             np.asarray(emb.lookup_ref(state, SPEC, idx)), rtol=1e-5, atol=1e-5)
 
 
+class TestLaneDenseLayout:
+    """Tables stored lane-dense (kernels/layout.py) through both grid
+    strategies of both kernels, in interpret mode, against the oracles on the
+    plain (rows, d) view — duplicates included, and both logical rows of one
+    lane row in one batch (an update of one must not lose the other)."""
+
+    @pytest.mark.parametrize("n_rows,d", [(50, 16), (37, 64), (21, 48), (9, 128)])
+    def test_pack_roundtrip_and_row_access(self, n_rows, d):
+        x = jax.random.normal(jax.random.PRNGKey(d), (n_rows, d))
+        packed = layout.pack(x)
+        assert packed.shape == (layout.lane_rows(n_rows, d), layout.lane_width(d))
+        assert packed.shape[-1] % 128 == 0
+        np.testing.assert_array_equal(np.asarray(layout.unpack(packed, n_rows, d)), x)
+        rows = jnp.asarray([n_rows - 1, 0, 1, 0])
+        np.testing.assert_array_equal(np.asarray(layout.take_rows(packed, rows, d)),
+                                      np.asarray(x[rows]))
+        vals = -jnp.ones((2, d))
+        out = layout.unpack(layout.set_rows(packed, jnp.asarray([0, 1]), vals, d), n_rows, d)
+        np.testing.assert_array_equal(np.asarray(out[:2]), np.asarray(vals))
+        np.testing.assert_array_equal(np.asarray(out[2:]), np.asarray(x[2:]))
+
+    @pytest.mark.parametrize("strategy", ["stream", "block"])
+    @pytest.mark.parametrize("n_rows,d,m", [(50, 16, 4), (37, 64, 3), (21, 48, 2)])
+    def test_lookup_parity(self, strategy, n_rows, d, m):
+        key = jax.random.PRNGKey(n_rows)
+        x = jax.random.normal(key, (n_rows, d))
+        idx = jax.random.randint(jax.random.fold_in(key, 1), (11, m), 0, n_rows)
+        idx = idx.at[0].set(jnp.asarray([0, 1, 0, 1][:m]))  # one lane row, both halves
+        out = embedding_bag_op(layout.pack(x), idx, dim=d, strategy=strategy)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(embedding_bag_ref(x, idx)),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("strategy", ["rows", "block"])
+    @pytest.mark.parametrize("n_rows,d", [(50, 16), (37, 64), (21, 48)])
+    def test_update_parity_shared_lane_rows(self, strategy, n_rows, d):
+        key = jax.random.PRNGKey(n_rows + d)
+        t = jax.random.normal(key, (n_rows, d))
+        a = jax.random.uniform(jax.random.fold_in(key, 1), (n_rows, d))
+        # rows 0/1 (and 2/3) share a lane row whenever d < 128: both halves
+        # of one lane row, each duplicated, in one batch; plus random rows
+        idx = jnp.concatenate([
+            jnp.asarray([[0, 1, 1, 0], [2, 2, 0, 5]]),
+            jax.random.randint(jax.random.fold_in(key, 2), (14, 4), 0, n_rows),
+        ])
+        g = jax.random.normal(jax.random.fold_in(key, 3), (idx.shape[0], d))
+        t2, a2 = sparse_adagrad_op(layout.pack(t), layout.pack(a), idx, g, lr=0.1,
+                                   dim=d, strategy=strategy)
+        rt, ra = sparse_adagrad_ref(t, a, idx, g, 0.1)
+        t2, a2 = layout.unpack(t2, n_rows, d), layout.unpack(a2, n_rows, d)
+        np.testing.assert_allclose(np.asarray(t2), np.asarray(rt), rtol=2e-5, atol=2e-5)
+        np.testing.assert_allclose(np.asarray(a2), np.asarray(ra), rtol=2e-5, atol=2e-5)
+        untouched = np.setdiff1d(np.arange(n_rows), np.asarray(idx))
+        np.testing.assert_array_equal(np.asarray(t2)[untouched], np.asarray(t)[untouched])
+        np.testing.assert_array_equal(np.asarray(a2)[untouched], np.asarray(a)[untouched])
+
+
 # ---------------------------------------------------------------------------
 # Shard plan + EmbeddingShards routing invariants
 # ---------------------------------------------------------------------------
@@ -175,8 +232,9 @@ class TestEmbeddingShards:
         plan = shards.plan_shards(SPEC, 4, 64)
         es = shards.EmbeddingShards.init(plan, jax.random.PRNGKey(0))
         packed = es.to_packed()
+        logical = emb.to_logical(state, SPEC)
         for k in state:
-            np.testing.assert_array_equal(np.asarray(packed[k]), np.asarray(state[k]))
+            np.testing.assert_array_equal(np.asarray(packed[k]), logical[k])
 
     def test_sharded_cycle_matches_single_table(self):
         """Plan-routed lookup + per-shard fused backward == the packed
@@ -195,7 +253,7 @@ class TestEmbeddingShards:
             np.asarray(emb.lookup_ref(state, SPEC, idx)), rtol=1e-5, atol=1e-5)
         for s in range(plan.n_shards):
             es.states[s] = shards.shard_update(plan, s, es.states[s], idx, g, 0.05)
-        oracle = emb.sparse_adagrad_update(state, SPEC, idx, g, 0.05)
+        oracle = emb.to_logical(emb.sparse_adagrad_update(state, SPEC, idx, g, 0.05), SPEC)
         packed = es.to_packed()
         for k in oracle:
             np.testing.assert_allclose(np.asarray(packed[k]), np.asarray(oracle[k]),
@@ -220,9 +278,8 @@ class TestRunnerWiring:
         assert all(np.isfinite(l) for l in out["train_loss"])
         assert out["emb_state"]["table"].shape == (SPEC.total_rows, CFG.embedding_dim)
         # the packed table moved away from init: updates landed through shards
-        init = emb.init_tables(SPEC, jax.random.PRNGKey(0))
-        assert not np.allclose(np.asarray(out["emb_state"]["acc"]),
-                               np.asarray(init["acc"]))
+        init = emb.to_logical(emb.init_tables(SPEC, jax.random.PRNGKey(0)), SPEC)
+        assert not np.allclose(np.asarray(out["emb_state"]["acc"]), init["acc"])
 
     def test_hogwild_sim_step_matches_manual_oracle(self):
         """One _train_iter of the sim (fused kernels by default) produces the

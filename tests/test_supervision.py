@@ -243,6 +243,26 @@ class TestSupervisorUnit:
         assert any(e.kind == "degraded" for e in sup.events)  # watch-only
         assert len(ticks) >= 1
 
+    def test_watch_loop_records_what_it_survives(self):
+        """A tick that raises does not kill the loop, and is never silent:
+        the count and the latest exception are kept for the run report."""
+        ticks = []
+
+        def bad_tick():
+            ticks.append(1)
+            raise ValueError(f"tick {len(ticks)} failed")
+
+        sup = Supervisor(SupervisorConfig(check_interval_s=0.005), tick=bad_tick)
+        assert sup.swallowed_errors() == {"count": 0, "last": None}
+        sup.start()
+        deadline = time.perf_counter() + 2.0
+        while len(ticks) < 3 and time.perf_counter() < deadline:
+            time.sleep(0.005)
+        sup.stop()
+        rec = sup.swallowed_errors()
+        assert rec["count"] == len(ticks) >= 3  # the loop outlived every failure
+        assert rec["last"] == repr(ValueError(f"tick {len(ticks)} failed"))
+
     def test_event_record_shape(self):
         ev = SupervisionEvent("stall", "shadow", 1.5, "why")
         assert (ev.kind, ev.name, ev.t, ev.reason) == ("stall", "shadow",
@@ -282,6 +302,8 @@ class TestRunnerChaos:
         kinds = [e.kind for e in out["supervision_events"]]
         assert "death" in kinds and "restart" in kinds
         assert not out["sync_degraded"]
+        # the watch loop itself survived nothing: its record rides the report
+        assert out["supervisor_errors"] == {"count": 0, "last": None}
         # provenance reached the membership log too
         assert any(e.kind == "sync_restart" for e in out["membership_events"])
         # and the cohort trained to completion regardless

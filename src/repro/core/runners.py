@@ -82,6 +82,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import checkpoint as ckpt
 from repro.core import algorithms
@@ -913,7 +914,18 @@ class ThreadedShadowRunner:
     (``make_shadow_round``), so this runner hosts any registered algorithm:
     EASGD pairs against the PS plane, slice-free decentralized mean +
     pull-backs (MA), the full block-momentum global step (BMUF), or rotating
-    pairwise exchanges (gossip)."""
+    pairwise exchanges (gossip).
+
+    ``run()`` counts the dense updates this lock-free interleaving erases,
+    per slot, by identity of the replica objects. ``overwritten_steps``: a
+    round's publish replaced a plane that a trainer step had reassigned
+    after the round captured it. ``overwritten_landings``: a trainer's
+    assignment replaced a plane that a publish had landed after the trainer
+    read it; this count is slot-owned and taken without a lock, so a publish
+    that lands between its check and the assignment (two bytecodes) goes
+    uncounted. Each trainer iteration and each shadow phase is a
+    ``jax.profiler.TraceAnnotation`` (``trainer.*``, ``shadow.*``), so a
+    profiler trace names what the host was doing on the device's clock."""
 
     def __init__(
         self,
@@ -1214,6 +1226,10 @@ class ThreadedShadowRunner:
         self.done = threading.Event()
         self.examples = 0  # guarded-by-writes: ex_lock — post-join reads are lock-free
         self.sync_count = 0  # guarded-by-writes: _sync_lock — post-join reads are lock-free
+        # erased dense updates per slot (class docstring)
+        self.overwritten_steps = [0] * self.R  # guarded-by-writes: _state_lock
+        # hogwild-race: ok — slot-owned counters
+        self.overwritten_landings = [0] * self.R
         # Failure-domain bookkeeping (DESIGN.md §10): captured trainer
         # exceptions (re-raised with slot provenance after join), dead sync-
         # thread incarnations, restart/degradation state, PS chaos tracking.
@@ -1383,20 +1399,24 @@ class ThreadedShadowRunner:
             # round outside it, then publish only if neither moved in the
             # meantime. A discarded round is harmless — by the isolation
             # property the next round simply syncs strictly fresher planes.
-            with self._state_lock:
+            with TraceAnnotation("shadow.capture"), self._state_lock:
                 epoch = self.membership.epoch
                 ids = self.membership.active_ids()
                 if ids.size == 0:
                     return 0
                 state_in = self.algo_state
                 sub = [self.w[j] for j in ids]
-            new_state, n = self._shadow_round(sub, state_in)
-            with self._state_lock:
+            captured = list(sub)  # the round replaces sub's entries in place
+            with TraceAnnotation("shadow.round"):
+                new_state, n = self._shadow_round(sub, state_in)
+            with TraceAnnotation("shadow.publish"), self._state_lock:
                 if (self.membership.epoch != epoch or self.algo_state is not state_in):
                     return 0  # membership/algo state moved under the round
                 self.algo_state = new_state
                 now_sync = time.perf_counter()
                 for k, j in enumerate(ids):
+                    if self.w[j] is not captured[k]:
+                        self.overwritten_steps[j] += 1  # a step landed since the capture
                     self.w[j] = sub[k]
                     # the slot's deltas just landed: its staleness age resets
                     self._last_sync_t[j] = now_sync
@@ -1729,69 +1749,79 @@ class ThreadedShadowRunner:
                 t_busy = time.perf_counter()
                 if sleep_s and (sleep_until is None or it < sleep_until):
                     time.sleep(sleep_s)  # injected degradation
-                staged = prep = None
-                if pipe is not None:
-                    staged, prep = pipe.consume(it)
-                batch = (
-                    prep["batch"]
-                    if prep is not None
-                    else ctr.gen_batch(self.cfg, self.teacher, self.seed + i, it, self.B)
-                )
-                if pipe is not None:
-                    # pipelined: per-shard planes staged ahead where the
-                    # hazard check allowed it; hazarded/drained shards rerun
-                    # serially right here — bitwise the same either way
-                    sparse_np = (
-                        prep["sparse"] if prep is not None else np.asarray(batch["sparse"])
+                with TraceAnnotation("trainer.batch"):
+                    staged = prep = None
+                    if pipe is not None:
+                        staged, prep = pipe.consume(it)
+                    batch = (
+                        prep["batch"]
+                        if prep is not None
+                        else ctr.gen_batch(self.cfg, self.teacher, self.seed + i, it, self.B)
                     )
-                    outs = [
-                        staged[s]
-                        if staged is not None and staged[s] is not None
-                        else self.emb.lookup_shard(s, sparse_np)
-                        for s in range(self.n_emb_shards)
-                    ]
-                    pooled = self.emb.assemble(outs)
-                    w, opt_state, loss, g_pooled = self._train_dense(
-                        self.w[i], self.opt_states[i], pooled, batch
-                    )
-                    # stage batch it+1.. while THIS step's dense compute and
-                    # sparse updates land (the overlap window)
-                    pipe.stage(it)
-                elif self.cache is not None:
-                    # hot-tier lookup through the per-PS caches (a miss that
-                    # beat the prefetch horizon promotes synchronously —
-                    # counted, never a stall of another trainer)
-                    sparse_np = np.asarray(batch["sparse"])
-                    pooled = self.emb.cached_lookup(sparse_np)
-                    w, opt_state, loss, g_pooled = self._train_dense(
-                        self.w[i], self.opt_states[i], pooled, batch
-                    )
-                else:
-                    # Lock-free read of the shared per-PS tables (Hogwild).
-                    w, opt_state, loss, g_pooled = self._train_one(
-                        self.w[i], self.opt_states[i], self.emb.tables(), batch
-                    )
-                self.w[i], self.opt_states[i] = w, opt_state
-                # Lock-free read-modify-write PER SHARD: concurrent writers to
-                # different PSs proceed independently; writers to the same PS
-                # can interleave and lose updates (the Hogwild property).
-                # A slot membership holds dead — policy-demoted — keeps
-                # training PRIVATE state (its iterations are the probe
-                # stream re-admission watches) but must not land its
-                # degraded gradients in the SHARED embedding state: same
-                # dead-slot no-op invariant as HogwildSim (DESIGN.md §8.2).
-                is_member = self.membership.status(i) == "active"
-                if is_member:
-                    for s in range(self.n_emb_shards):
-                        # routed through the PS failure domain: a healthy
-                        # shard takes the plain lock-free swap; a failed one
-                        # retries with backoff then DROPS the update (counted)
-                        # — training never blocks on a dead PS
-                        if self.cache is not None:
-                            self.emb.cached_update(s, sparse_np, g_pooled, self.emb_lr)
-                        else:
-                            self.emb.try_update(s, self._emb_updates[s], batch["sparse"], g_pooled)
-                lv = float(loss)
+                with TraceAnnotation("trainer.step"):
+                    w_in = self.w[i]
+                    if pipe is not None:
+                        # pipelined: per-shard planes staged ahead where the
+                        # hazard check allowed it; hazarded/drained shards rerun
+                        # serially right here — bitwise the same either way
+                        sparse_np = (
+                            prep["sparse"] if prep is not None else np.asarray(batch["sparse"])
+                        )
+                        outs = [
+                            staged[s]
+                            if staged is not None and staged[s] is not None
+                            else self.emb.lookup_shard(s, sparse_np)
+                            for s in range(self.n_emb_shards)
+                        ]
+                        pooled = self.emb.assemble(outs)
+                        w, opt_state, loss, g_pooled = self._train_dense(
+                            w_in, self.opt_states[i], pooled, batch
+                        )
+                        # stage batch it+1.. while THIS step's dense compute and
+                        # sparse updates land (the overlap window)
+                        pipe.stage(it)
+                    elif self.cache is not None:
+                        # hot-tier lookup through the per-PS caches (a miss that
+                        # beat the prefetch horizon promotes synchronously —
+                        # counted, never a stall of another trainer)
+                        sparse_np = np.asarray(batch["sparse"])
+                        pooled = self.emb.cached_lookup(sparse_np)
+                        w, opt_state, loss, g_pooled = self._train_dense(
+                            w_in, self.opt_states[i], pooled, batch
+                        )
+                    else:
+                        # Lock-free read of the shared per-PS tables (Hogwild).
+                        w, opt_state, loss, g_pooled = self._train_one(
+                            w_in, self.opt_states[i], self.emb.tables(), batch
+                        )
+                    if self.w[i] is not w_in:
+                        # a publish landed since the read: this assignment erases it
+                        self.overwritten_landings[i] += 1
+                    self.w[i], self.opt_states[i] = w, opt_state
+                with TraceAnnotation("trainer.update"):
+                    # Lock-free read-modify-write PER SHARD: concurrent writers to
+                    # different PSs proceed independently; writers to the same PS
+                    # can interleave and lose updates (the Hogwild property).
+                    # A slot membership holds dead — policy-demoted — keeps
+                    # training PRIVATE state (its iterations are the probe
+                    # stream re-admission watches) but must not land its
+                    # degraded gradients in the SHARED embedding state: same
+                    # dead-slot no-op invariant as HogwildSim (DESIGN.md §8.2).
+                    is_member = self.membership.status(i) == "active"
+                    if is_member:
+                        for s in range(self.n_emb_shards):
+                            # routed through the PS failure domain: a healthy
+                            # shard takes the plain lock-free swap; a failed one
+                            # retries with backoff then DROPS the update (counted)
+                            # — training never blocks on a dead PS
+                            if self.cache is not None:
+                                self.emb.cached_update(s, sparse_np, g_pooled, self.emb_lr)
+                            else:
+                                self.emb.try_update(
+                                    s, self._emb_updates[s], batch["sparse"], g_pooled
+                                )
+                with TraceAnnotation("trainer.loss"):
+                    lv = float(loss)
                 losses[i].append(lv)
                 # slot-owned loss EMA (quality signal, DESIGN.md §14)
                 prev = self._loss_ema[i]
@@ -1862,14 +1892,15 @@ class ThreadedShadowRunner:
                 # (stage promotions/evictions while trainers compute), and
                 # PS snapshots ride its cadence (O(1) reference grabs;
                 # O(hot_rows) merged() drains in cached mode)
-                _prefetch_step()
-                if self._shadow_rounds % self.ps_snapshot_every == 0:
-                    self.emb.snapshot_all()
-                # the controllers ride the shadow cadence: membership AND
-                # the cohort mode are re-evaluated every background round,
-                # training never blocks on either
-                _policy_step()
-                _mode_step(gen)
+                with TraceAnnotation("shadow.upkeep"):
+                    _prefetch_step()
+                    if self._shadow_rounds % self.ps_snapshot_every == 0:
+                        self.emb.snapshot_all()
+                    # the controllers ride the shadow cadence: membership AND
+                    # the cohort mode are re-evaluated every background round,
+                    # training never blocks on either
+                    _policy_step()
+                    _mode_step(gen)
                 if self.sync_sleep_s:
                     time.sleep(self.sync_sleep_s)
 
@@ -2047,6 +2078,8 @@ class ThreadedShadowRunner:
             "wall_s": wall,
             "train_loss": [float(np.mean(l[-50:])) if l else float("nan") for l in losses],
             "sync_count": self.sync_count,
+            "overwritten_steps": list(self.overwritten_steps),
+            "overwritten_landings": list(self.overwritten_landings),
             "avg_sync_gap": total_iters / max(self.sync_count, 1),
             "per_trainer_eps": [
                 self.B * self.iter_count[i] / trainer_wall[i]

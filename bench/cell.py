@@ -4,9 +4,23 @@
 ``bench/traffic/<traffic>.json`` the training job run on it, and
 ``bench/workloads/<cell>.json`` the limits of the cell's comparison. A
 metric applies to the cells its ``workloads`` key lists, or to every cell
-without one; a per-layer metric is read by ``bench/metrics/<name>.py``."""
+without one; a per-layer metric is read by ``bench/metrics/<name>.py``.
+
+A configuration's ``interaction`` key names
+``bench/reference/interactions/<interaction>.py``, the arithmetic between
+the bottom and the top MLP. Its module gives ``out_dim``, ``n_keys``,
+``init``, ``apply``, ``forward_flops`` and ``PROGRAM_KEYS``; ``dot.py``
+there says what each is. A configuration's ``multi_hot`` is one bag size
+for every table or a list of one per table (``bench/reference/shapes.py``).
+So a new DLRM architecture is new files: its config, its interaction, its
+traffic and workload files, its metric readers and its entries in
+``BENCHMARK.json``. Metric readers and interactions are loaded from their
+files by ``load_module``; a name with no file fails there, naming the
+file."""
 from __future__ import annotations
 
+import functools
+import importlib.util
 import json
 from dataclasses import dataclass
 from pathlib import Path
@@ -25,6 +39,18 @@ class Cell:
     limits: Dict[str, float]
     end_to_end: List[dict]
     per_layer: List[dict]
+
+
+@functools.lru_cache(maxsize=None)
+def load_module(directory: Path, name: str):
+    """The module ``<directory>/<name>.py``, loaded from its file once per process."""
+    path = Path(directory) / f"{name}.py"
+    if not path.is_file():
+        raise FileNotFoundError(f"no file {path} for {name!r}")
+    spec = importlib.util.spec_from_file_location(f"bench_{Path(directory).name}_{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def _read(path: Path) -> dict:

@@ -14,5 +14,6 @@ def read(ctx):
     secs = ctx.trace.op_seconds(KERNEL)
     if secs <= 0:
         return None
-    moved = nbytes.total(ctx.window_ids(), ctx.shapes.dim, nbytes.lookup_bytes)
+    moved = nbytes.total(ctx.window_ids(), ctx.shapes.dim, nbytes.lookup_bytes,
+                         ctx.shapes.bags)
     return 100.0 * moved / ctx.peaks["hbm_bytes_per_s"] / secs

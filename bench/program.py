@@ -3,47 +3,69 @@
 The runner is built exactly as ``launch/train.py``'s ``run_dlrm`` builds it
 for ``--threaded``. Its state is read through the program's own layout: the
 plan that assigns tables to embedding shards, and lane-dense rows (``128 //
-dim`` logical rows to a 128-lane row). ``CheckRun`` drives the runner's own
-``run`` through a fixed interleaving of its threads, so that the reference
-can follow it, and reads the numbers that ``bench/check.py`` compares."""
+dim`` logical rows to a 128-lane row); a batch's ids in the layout of the
+configuration's bag sizes (``bench/reference/shapes.py``). ``CheckRun``
+drives the runner's own ``run`` through a fixed interleaving of its
+threads, so that the reference can follow it, and reads the numbers that
+``bench/check.py`` compares."""
 from __future__ import annotations
 
+import dataclasses
 import functools
 import threading
 import time
-from dataclasses import replace
 from typing import Dict, List, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from bench.reference.dlrm import pool
+from bench.reference.shapes import column_tables, interaction
 from bench.reference.train import Readings
 
 LANE = 128
 
 
-def build_runner(config: dict, traffic: dict, seed: int):
-    """The threaded runner for one configuration file and one traffic file."""
-    from repro import optim
-    from repro.configs import dlrm_ctr
-    from repro.core.membership import FaultSpec
-    from repro.core.runners import ThreadedShadowRunner
-    from repro.core.sync import SyncConfig
+def _as_given(v):
+    return tuple(v) if isinstance(v, list) else v
 
-    cfg = replace(
+
+def program_config(config: dict):
+    """The program's ``DLRMConfig`` for one configuration file: its sizes, and
+    the keys its interaction file lists in ``PROGRAM_KEYS``, as they are."""
+    from repro.configs import dlrm_ctr
+
+    keys = interaction(config["interaction"]).PROGRAM_KEYS
+    fields = {f.name for f in dataclasses.fields(dlrm_ctr.CONFIG)}
+    missing = [k for k in keys if k not in fields]
+    if missing:
+        raise KeyError(f"interaction {config['interaction']!r} passes {missing} to the "
+                       f"program, whose DLRMConfig has no such field")
+    return dataclasses.replace(
         dlrm_ctr.CONFIG,
         name=config["name"],
         n_dense_features=config["n_dense_features"],
         n_sparse_features=len(config["table_sizes"]),
         embedding_dim=config["embedding_dim"],
         table_sizes=tuple(config["table_sizes"]),
-        multi_hot=config["multi_hot"],
+        multi_hot=_as_given(config["multi_hot"]),
         bottom_mlp=tuple(config["bottom_mlp"]),
         top_mlp=tuple(config["top_mlp"]),
         interaction=config["interaction"],
         dtype=config["dtype"],
+        **{k: _as_given(config[k]) for k in keys},
     )
+
+
+def build_runner(config: dict, traffic: dict, seed: int):
+    """The threaded runner for one configuration file and one traffic file."""
+    from repro import optim
+    from repro.core.membership import FaultSpec
+    from repro.core.runners import ThreadedShadowRunner
+    from repro.core.sync import SyncConfig
+
+    cfg = program_config(config)
     sync_cfg = SyncConfig(
         algo=traffic["algo"], mode=traffic["mode"], gap=traffic["sync_gap"],
         alpha=traffic["alpha"], delay=traffic["sync_delay"])
@@ -101,23 +123,26 @@ def shard_row_index(plan, ids: List[np.ndarray], pad: Sequence[int]):
     return parts
 
 
-def pooled_reader(plan, tables: Sequence[jnp.ndarray]):
-    """``ids (B, F, m) -> pooled (B, F, dim)`` over the program's tables."""
+def pooled_reader(plan, tables: Sequence[jnp.ndarray], bags: Sequence[int]):
+    """``ids -> pooled (B, F, dim)`` over the program's tables, for ids laid
+    out by ``bags``, one bag size per table."""
     dim, p = plan.spec.dim, LANE // plan.spec.dim
-    off = jnp.asarray(plan.feature_local_offset, jnp.int32)
-    shard_of = np.asarray(plan.feature_shard)
+    col = column_tables(bags)
+    off = jnp.asarray(np.asarray(plan.feature_local_offset)[col], jnp.int32)
+    shard_of = np.asarray(plan.feature_shard)[col]
+    bags = tuple(bags)
 
     @jax.jit
     def pooled(tables, ids):
-        local = ids + off[None, :, None]
-        out = jnp.zeros(ids.shape[:2] + (dim,), jnp.float32)
+        local = ids.reshape(ids.shape[0], -1) + off[None, :]
+        out = jnp.zeros(local.shape + (dim,), jnp.float32)
         for s, t in enumerate(tables):
-            mask = jnp.asarray(shard_of == s)[None, :, None]
+            mask = jnp.asarray(shard_of == s)[None, :]
             loc = jnp.where(mask, local, 0)
             rows = jnp.take(t, loc // p, axis=0).reshape(*loc.shape, p, dim)
             rows = jnp.take_along_axis(rows, (loc % p)[..., None, None], axis=-2)[..., 0, :]
-            out = out + jnp.where(mask, jnp.sum(rows, axis=2), 0.0)
-        return out
+            out = out + jnp.where(mask[..., None], rows, 0.0)
+        return pool(out, bags)
 
     tables = tuple(tables)
     return lambda ids: pooled(tables, ids)
@@ -148,7 +173,7 @@ class CheckRun:
     the shadow thread's heartbeat stays fresh."""
 
     def __init__(self, runner, batches: Dict[Tuple[int, int], dict], sched, touched,
-                 timeout_s: float = 60.0):
+                 bags: Sequence[int], timeout_s: float = 60.0):
         from repro.embeddings import shards
         from repro.embeddings.table import init_tables
 
@@ -157,8 +182,7 @@ class CheckRun:
         self.batches = batches
         self.n_sweeps = max(ev[2] for ev in self.sched if ev[0] == "train") + 1
         plan = runner.plan
-        m = plan.spec.multi_hot
-        pad = [self.n_sweeps * runner.R * runner.B * len(b) * m for b in plan.bins]
+        pad = [self.n_sweeps * runner.R * runner.B * sum(bags[f] for f in b) for b in plan.bins]
         self.parts = shard_row_index(plan, touched, pad)
         states = jax.eval_shape(lambda k: shards.shard_states(plan, init_tables(plan.spec, k)),
                                 jax.random.PRNGKey(0))

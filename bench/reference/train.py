@@ -20,7 +20,7 @@ import numpy as np
 
 from bench.reference import ctr
 from bench.reference import dlrm as ref
-from bench.reference.shapes import Shapes
+from bench.reference.shapes import Shapes, blocks
 
 
 @dataclass(frozen=True)
@@ -60,7 +60,7 @@ def schedule(n_trainers: int, n_sweeps: int) -> List[tuple]:
 def touched_ids(shapes: Shapes, batches: Sequence[dict]) -> List[np.ndarray]:
     """Per table, the sorted distinct ids the batches touch."""
     ids = np.concatenate([np.asarray(b["sparse"]) for b in batches], axis=0)
-    return [np.unique(ids[:, f, :]) for f in range(shapes.n_tables)]
+    return [np.unique(b) for b in blocks(ids, shapes.bags)]
 
 
 def table_segments(shapes: Shapes, touched: List[np.ndarray], pad_to: int = 0):
@@ -92,10 +92,10 @@ def _step(shapes, lr, emb_lr, eps, p, acc_p, table, acc_t, touched_rows, batch):
     """One trainer step on the touched rows of the collection: ``table`` and
     ``acc_t`` hold global row ``touched_rows[k]`` at row ``k``."""
     rows = jnp.searchsorted(touched_rows, ref.global_rows(shapes, batch["sparse"]))
-    pooled = ref.pooled_lookup(table, rows)
+    pooled = ref.pooled_lookup(table, rows, shapes.bags)
     loss, g_p, g_pooled = ref.loss_and_grads(shapes, p, batch, pooled)
     p, acc_p = ref.adagrad(p, acc_p, g_p, lr, eps)
-    table, acc_t = ref.sparse_adagrad(table, acc_t, rows, g_pooled, emb_lr, eps)
+    table, acc_t = ref.sparse_adagrad(table, acc_t, rows, g_pooled, emb_lr, eps, shapes.bags)
     return loss, p, acc_p, table, acc_t
 
 

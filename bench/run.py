@@ -12,8 +12,15 @@ entropy of the model the run hands back is measured, the program's state is
 freed, and the reference replays the check run (``bench/check.py`` decides
 ``correct``). The last line of standard output is one JSON object; with
 ``--trace 1`` the window runs under the profiler and the per-layer metrics
-(``bench/metrics/<name>.py``) replace the end-to-end ones. A run fails when
-JAX finds no TPU, or fewer chips than the cell asks for."""
+(``bench/metrics/<name>.py``, each a ``read(ctx)`` of a ``Context`` that
+returns the number or None) replace the end-to-end ones. The configuration's
+``interaction`` names ``bench/reference/interactions/<name>.py``, which the
+reference's forward, its initial weights, the FLOP count and the program's
+config read (``out_dim``, ``n_keys``, ``init``, ``apply``,
+``forward_flops``, ``PROGRAM_KEYS``: ``dot.py`` there documents each); its
+``multi_hot``, one bag size or one per table, sets the ids' layout. A
+run fails when JAX finds no TPU, or fewer chips than the cell asks for,
+and in set-up when a named file is missing."""
 from __future__ import annotations
 
 import time
@@ -24,7 +31,6 @@ import argparse  # noqa: E402
 import contextlib  # noqa: E402
 import faulthandler  # noqa: E402
 import gc  # noqa: E402
-import importlib.util  # noqa: E402
 import json  # noqa: E402
 import math  # noqa: E402
 import os  # noqa: E402
@@ -139,7 +145,8 @@ class Context:
         self._ids = None
 
     def window_ids(self):
-        """The (B, F, m) ids of every batch the window trained on."""
+        """The ids of every batch the window trained on, in the layout of
+        ``shapes.bags``: (B, F, m), or (B, sum of m_f) for bag sizes per table."""
         if self._ids is None:
             import numpy as np
             from bench.reference import ctr
@@ -152,11 +159,9 @@ class Context:
 
 
 def read_metric(name: str, ctx: Context):
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name}", BENCH / "metrics" / f"{name}.py")
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read(ctx)
+    from bench.cell import load_module
+
+    return load_module(BENCH / "metrics", name).read(ctx)
 
 
 def log(msg: str) -> None:
@@ -176,6 +181,7 @@ class CheckInputs:
             for ev in self.sched if ev[0] == "train"
         }
         self.touched = touched_ids(shapes, list(self.batches.values()))
+        self.bags = shapes.bags
 
 
 def build(cell, seed: int, plant=None):
@@ -204,7 +210,8 @@ def check_program(runner, inputs: CheckInputs):
 
     try:
         with deadline("the check run", 180, runner), window_end(runner):
-            return program.CheckRun(runner, inputs.batches, inputs.sched, inputs.touched).run(), None
+            return program.CheckRun(runner, inputs.batches, inputs.sched, inputs.touched,
+                                    inputs.bags).run(), None
     except (program.CheckAbort, RuntimeError) as e:
         # the trainers' exceptions hold their frames, and with them tables
         runner._trainer_excs = []
@@ -283,7 +290,8 @@ def run_cell(cell, seed: int, seconds: float, trace: bool, t0: float, plant=None
     log(f"window {window_s:.3f}s, {iter_count} iterations, peak {peak_bytes / GB:.3f} GB")
     t_trace = time.perf_counter()
     ne = eval_ne(shapes, program.leaf_dict(out["w"][0]),
-                 program.pooled_reader(runner.plan, [s["table"] for s in runner.emb.states]),
+                 program.pooled_reader(runner.plan, [s["table"] for s in runner.emb.states],
+                                       shapes.bags),
                  teacher, tr["eval_seed"], tr["eval_batches"], tr["eval_batch_size"])
     counters = {k: out[k] for k in ("sync_count", "sync_rounds", "dropped_updates",
                                     "sync_restarts", "supervisor_errors")}
